@@ -173,9 +173,16 @@ def compile_step(specs: tuple[FeatureSpec, ...], m: DatasetManifest,
     if mesh is None:
         return jax.jit(fn, **kw)
 
+    # XLA cannot partition a Mosaic kernel, so the step is written per
+    # device: each one runs every feature on its own (shards / devices,
+    # chunk, ...) slice, and the step holds no collective at all.
+    # check_vma is off because pallas_call output shapes carry no
+    # varying-axes annotation; every output is per-device by construction
+    n_in = 3 if raw else 2
+    fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(data_axes),) * n_in,
+                       out_specs=P(data_axes), check_vma=False)
     shard = NamedSharding(mesh, P(data_axes))
-    in_shardings = (shard, shard, shard) if raw else (shard, shard)
-    return jax.jit(fn, in_shardings=in_shardings,
+    return jax.jit(fn, in_shardings=(shard,) * n_in,
                    out_shardings=shard, **kw)
 
 

@@ -459,12 +459,32 @@ register(FeatureSpec(
 SPECTRUM_PERCENTILES = (1.0, 5.0, 25.0, 50.0, 75.0, 95.0, 99.0)
 
 
+def _percentile_taps(n: int):
+    """np.percentile's 'linear' interpolation over ``n`` sorted values,
+    worked out on the host: (low index, high index, low weight, high
+    weight) per SPECTRUM_PERCENTILES entry.
+
+    Left to XLA (as ``jnp.percentile`` does), this arithmetic on
+    constants is folded differently by different compilations of the
+    same step — on a TPU, the shard_map step of a mesh and the one-chip
+    step disagreed in the last bit of the 95th percentile's weight — so
+    the step would not be bitwise-identical across device counts."""
+    pos = np.asarray(SPECTRUM_PERCENTILES, np.float64) / 100.0 * (n - 1)
+    lo = np.floor(pos).astype(np.int64)
+    hi = np.minimum(lo + 1, n - 1)
+    w = pos - lo
+    return lo, hi, (1.0 - w).astype(np.float32), w.astype(np.float32)
+
+
 def _percentiles_compute(ctx: FeatureContext) -> jnp.ndarray:
     p = ctx.params
     db = 10.0 * jnp.log10(jnp.maximum(ctx.frame_psd, 1e-30)) + p.gain_db
-    q = jnp.asarray(SPECTRUM_PERCENTILES, db.dtype)
-    pct = jnp.percentile(db, q, axis=-2)       # (n_pct, batch, n_bins)
-    return jnp.moveaxis(pct, 0, 1)             # (batch, n_pct, n_bins)
+    srt = jnp.sort(db, axis=-2)                # (batch, n_frames, n_bins)
+    lo, hi, w_lo, w_hi = _percentile_taps(db.shape[-2])
+    pct = srt[:, lo] * w_lo[:, None] + srt[:, hi] * w_hi[:, None]
+    # (batch, n_pct, n_bins); NaNs sort last, so a bin with any NaN
+    # frame is NaN at every level, as in jnp.percentile
+    return jnp.where(jnp.isnan(srt[:, -1:]), jnp.nan, pct)
 
 
 register(FeatureSpec(

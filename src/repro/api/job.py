@@ -27,7 +27,7 @@ import copy
 import dataclasses
 
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.core.manifest import DatasetManifest, ShardPlan, plan
 from repro.core.params import DepamParams
@@ -160,7 +160,16 @@ class SoundscapeJob:
 
     def on(self, mesh: Mesh | None,
            data_axes: tuple[str, ...] = ("data",)) -> "SoundscapeJob":
-        """Shard the job over ``data_axes`` of a device mesh."""
+        """Shard the job over ``data_axes`` of a device mesh.
+
+        The mesh is taken with ``Auto`` axes whatever it was built with
+        (``jax.make_mesh`` defaults to ``Explicit``): the step is a
+        ``shard_map`` over the data axes and the reduce update is
+        partitioned by XLA, and explicit sharding types reject the
+        latter's per-shard ``vmap``."""
+        if mesh is not None and any(t != AxisType.Auto
+                                    for t in mesh.axis_types):
+            mesh = Mesh(mesh.devices, mesh.axis_names)
         self._mesh = mesh
         self._data_axes = tuple(data_axes)
         return self

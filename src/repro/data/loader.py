@@ -170,9 +170,7 @@ class SpeculativeLoader:
                     continue
                 try:
                     results[i] = fut.result(timeout=budget)
-                # cf.TimeoutError is NOT the builtin TimeoutError until
-                # Python 3.11; catch both spellings.
-                except (cf.TimeoutError, TimeoutError):
+                except TimeoutError:
                     # straggler: launch a duplicate, first SUCCESS wins
                     with self._lock:
                         self.speculated += 1

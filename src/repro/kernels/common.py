@@ -7,7 +7,9 @@ work in tests, benchmarks and on real hardware.
 """
 from __future__ import annotations
 
+import collections
 import functools
+import re
 
 import jax
 import numpy as np
@@ -18,6 +20,19 @@ from repro.core.params import PCM_DECODE_SCALE
 @functools.cache
 def use_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def tpu_kernel_calls(hlo_text: str) -> collections.Counter:
+    """Pallas kernels in a compiled TPU program (``compiled.as_text()``),
+    counted by the ``name=`` each ``pallas_call`` was given.  A
+    ``tpu_custom_call`` without a pallas_call name counts under "?".
+    An interpret-mode program holds none."""
+    names = collections.Counter()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            hit = re.search(r'op_name="[^"]*/(\w+)/pallas_call"', line)
+            names[hit.group(1) if hit else "?"] += 1
+    return names
 
 
 def dequantize(pcm, scales=None):

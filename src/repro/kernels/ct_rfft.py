@@ -81,7 +81,7 @@ def _body(x_ref, w_ref, c1_ref, s1_ref, tr_ref, ti_ref, c2_ref, s2_ref,
 
 def _body_q(x_ref, q_ref, w_ref, c1_ref, s1_ref, tr_ref, ti_ref, c2_ref,
             s2_ref, sc_ref, o_ref, *, n1: int, n2: int):
-    """int16 variant: ``q_ref`` (block_frames, 1) holds the per-frame
+    """int16 variant: ``q_ref`` (block_frames, 1, 1) holds the per-frame
     decode scale; one convert + one multiply in VMEM (the host decode's
     exact rounding) before the same two-stage CT chain."""
     _chain(x_ref[...].astype(jnp.float32) * q_ref[...], w_ref, c1_ref,
@@ -93,7 +93,7 @@ def _chain(x, w_ref, c1_ref, s1_ref, tr_ref, ti_ref, c2_ref, s2_ref,
            sc_ref, o_ref, *, n1: int, n2: int):
     bf = x.shape[0]
     n2h = c2_ref.shape[1]
-    a = (x.reshape(bf, n1, n2) * w_ref[...][None])
+    a = x * w_ref[...][None]                   # x: (bf, n1, n2)
     # Stage 1 (real input): Y = D1 @ A, batched over frames.
     yr = jnp.einsum("nk,bnm->bkm", c1_ref[...], a,
                     precision=_PREC, preferred_element_type=jnp.float32)
@@ -145,11 +145,14 @@ def ct_frame_psd(frames: jnp.ndarray, p, n1: int | None = None,
                         else frames.astype(jnp.float32), 0, fpad)
     if p.window_size < nfft:
         x = common.pad_axis(x, 1, nfft)
+    # the row-major (n1, n2) split of each frame happens here, in XLA:
+    # Mosaic refuses the in-kernel (bf, nfft) -> (bf, n1, n2) shape cast
+    x = x.reshape(fpad, n1, n2)
 
     grid = (fpad // block_frames,)
     full = lambda shape: pl.BlockSpec(shape, lambda i: tuple(0 for _ in shape))
     in_specs = [
-        pl.BlockSpec((block_frames, nfft), lambda i: (i, 0)),
+        pl.BlockSpec((block_frames, n1, n2), lambda i: (i, 0, 0)),
         full((n1, n2)),          # window
         full((n1, n1)), full((n1, n1)),      # stage-1 DFT
         full((n1, n2)), full((n1, n2)),      # twiddle
@@ -163,9 +166,9 @@ def ct_frame_psd(frames: jnp.ndarray, p, n1: int | None = None,
             sq = jnp.full((nf,), common.PCM_DECODE_SCALE, jnp.float32)
         else:
             sq = jnp.asarray(scales, jnp.float32)
-        sq = common.pad_axis(sq, 0, fpad).reshape(fpad, 1)
-        in_specs.insert(1, pl.BlockSpec((block_frames, 1),
-                                        lambda i: (i, 0)))
+        sq = common.pad_axis(sq, 0, fpad).reshape(fpad, 1, 1)
+        in_specs.insert(1, pl.BlockSpec((block_frames, 1, 1),
+                                        lambda i: (i, 0, 0)))
         operands.insert(1, sq)
         body = functools.partial(_body_q, n1=n1, n2=n2)
 
@@ -176,6 +179,7 @@ def ct_frame_psd(frames: jnp.ndarray, p, n1: int | None = None,
         out_specs=pl.BlockSpec((block_frames, n2h * n1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((fpad, n2h * n1), jnp.float32),
         interpret=interpret,
+        name="ct_frame_psd",
     )(*operands)
 
     return out[:nf, : p.n_bins]

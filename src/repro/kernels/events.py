@@ -24,7 +24,8 @@ frame wins ties) and ``peak_bin`` is that frame's argmax PSD bin.
 
 One scan body (:func:`scan_events`, pure jnp — comparisons, selects and
 integer adds only, no rounding anywhere) is shared verbatim by the
-Pallas kernel and the XLA fallback, so the two paths are bitwise-equal
+Pallas kernel and the XLA fallback, which differ only in how a frame is
+read, so the two paths are bitwise-equal
 by construction; ``tests/test_events.py`` additionally pins both to a
 NumPy oracle under hypothesis.  The kernel runs the scan per batch block
 in VMEM (grid over records) so the event stream compacts on-device —
@@ -42,44 +43,49 @@ from jax.experimental import pallas as pl
 from . import common
 
 N_EVENT_COLS = 4          # onset_frame, n_frames, peak_bin, peak_db
+LANES = 128               # frames per VMEM tile row in the Pallas kernel
 
 
-def scan_events(spl: jnp.ndarray, peak_bin: jnp.ndarray, *,
-                n_frames: int, threshold_db: float, hysteresis_db: float,
-                min_len: int, capacity: int
-                ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The shared scan body: (B, F) SPL/peak-bin -> (counts, rows).
+def scan_events(read, *, b: int, f_total: int, n_frames: int,
+                threshold_db: float, hysteresis_db: float, min_len: int,
+                capacity: int) -> tuple[jnp.ndarray, tuple[jnp.ndarray, ...]]:
+    """The shared scan body over ``f_total`` frames of ``b`` records.
 
-    ``spl`` may carry padding frames beyond ``n_frames`` as long as they
+    ``read(f)`` returns frame ``f``'s SPL (float32) and peak bin
+    (int32), each ``(b, 1)``: the XLA fallback slices its (B, F) arrays,
+    the Pallas body loads the frame from its VMEM block.  Returns
+    ``(counts (b, 1) int32, cols)`` with ``cols`` the four ``(b,
+    capacity)`` float32 row columns (onset, duration, peak bin, peak
+    dB); every state array is 2-D with records on the sublane axis, the
+    layout the TPU lowering accepts.
+
+    The SPL may carry padding frames beyond ``n_frames`` as long as they
     are ``-inf`` (strictly below any finite close level): a pad frame
     then closes a still-open event with the exact same duration the
     record-end closure below produces, and can never open one — the
     padded and unpadded scans agree bitwise.
     """
-    b, f_total = spl.shape
     k = capacity
     thr = jnp.float32(threshold_db)
     lo = jnp.float32(threshold_db) - jnp.float32(hysteresis_db)
-    slots = jnp.arange(k, dtype=jnp.int32)[None, :]        # (1, K)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (b, k), 1)
 
-    def emit(count, rows, qualify, start, dur, pk_bin, pk_db):
+    def emit(count, cols, qualify, start, dur, pk_bin, pk_db):
         """Append one closing event per record where ``qualify``."""
-        row = jnp.stack([start.astype(jnp.float32),
-                         dur.astype(jnp.float32),
-                         pk_bin.astype(jnp.float32),
-                         pk_db], axis=-1)                  # (B, 4)
-        hot = qualify[:, None] & (slots == count[:, None])  # count < K only
-        rows = jnp.where(hot[:, :, None], row[:, None, :], rows)
-        return count + qualify.astype(jnp.int32), rows
+        hot = qualify & (slots == count)                  # count < K only
+        vals = (start.astype(jnp.float32), dur.astype(jnp.float32),
+                pk_bin.astype(jnp.float32), pk_db)
+        cols = tuple(jnp.where(hot, v, c) for v, c in zip(vals, cols))
+        return count + qualify.astype(jnp.int32), cols
 
     def body(f, st):
-        in_ev, start, pk_db, pk_bin, count, rows = st
-        s = jax.lax.dynamic_slice_in_dim(spl, f, 1, axis=1)[:, 0]
-        pb = jax.lax.dynamic_slice_in_dim(peak_bin, f, 1, axis=1)[:, 0]
+        open_, start, pk_db, pk_bin, count, cols = st
+        in_ev = open_ != 0
+        s, pb = read(f)
         # close: first frame below the hysteresis level ends the event
         closing = in_ev & (s < lo)
         dur = f - start
-        count, rows = emit(count, rows, closing & (dur >= min_len),
+        count, cols = emit(count, cols, closing & (dur >= min_len),
                            start, dur, pk_bin, pk_db)
         in_ev = in_ev & ~closing
         # continue: track the peak frame (strict >, first frame wins ties)
@@ -91,21 +97,25 @@ def scan_events(spl: jnp.ndarray, peak_bin: jnp.ndarray, *,
         start = jnp.where(opening, f, start)
         pk_db = jnp.where(opening, s, pk_db)
         pk_bin = jnp.where(opening, pb, pk_bin)
-        return in_ev | opening, start, pk_db, pk_bin, count, rows
+        return ((in_ev | opening).astype(jnp.int32), start, pk_db, pk_bin,
+                count, cols)
 
-    init = (jnp.zeros((b,), jnp.bool_),                    # in_event
-            jnp.zeros((b,), jnp.int32),                    # start frame
-            jnp.full((b,), -jnp.inf, jnp.float32),         # peak SPL
-            jnp.zeros((b,), jnp.int32),                    # peak bin
-            jnp.zeros((b,), jnp.int32),                    # count
-            jnp.zeros((b, k, N_EVENT_COLS), jnp.float32))  # rows
-    in_ev, start, pk_db, pk_bin, count, rows = jax.lax.fori_loop(
+    # the open flag is carried as int32: Mosaic cannot carry an i1
+    # vector through a loop
+    one = (b, 1)
+    init = (jnp.zeros(one, jnp.int32),                      # event open
+            jnp.zeros(one, jnp.int32),                      # start frame
+            jnp.full(one, -jnp.inf, jnp.float32),           # peak SPL
+            jnp.zeros(one, jnp.int32),                      # peak bin
+            jnp.zeros(one, jnp.int32),                      # count
+            tuple(jnp.zeros((b, k), jnp.float32)
+                  for _ in range(N_EVENT_COLS)))            # row columns
+    open_, start, pk_db, pk_bin, count, cols = jax.lax.fori_loop(
         0, f_total, body, init)
     # events still open at the TRUE record end close there
     dur = jnp.int32(n_frames) - start
-    count, rows = emit(count, rows, in_ev & (dur >= min_len),
-                       start, dur, pk_bin, pk_db)
-    return count, rows
+    return emit(count, cols, (open_ != 0) & (dur >= min_len),
+                start, dur, pk_bin, pk_db)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -115,20 +125,46 @@ def detect_events_xla(spl: jnp.ndarray, peak_bin: jnp.ndarray, *,
                       min_len: int, capacity: int):
     """XLA fallback (reference form, kernels/ref.py discipline): the
     scan body jitted directly, no padding, no grid."""
-    return scan_events(spl, peak_bin, n_frames=spl.shape[1],
-                       threshold_db=threshold_db,
-                       hysteresis_db=hysteresis_db,
-                       min_len=min_len, capacity=capacity)
+    b, n_frames = spl.shape
+
+    def read(f):
+        return (jax.lax.dynamic_slice_in_dim(spl, f, 1, axis=1),
+                jax.lax.dynamic_slice_in_dim(peak_bin, f, 1, axis=1))
+
+    count, cols = scan_events(
+        read, b=b, f_total=n_frames, n_frames=n_frames,
+        threshold_db=threshold_db, hysteresis_db=hysteresis_db,
+        min_len=min_len, capacity=capacity)
+    return count[:, 0], jnp.stack(cols, axis=-1)
 
 
 def _events_body(spl_ref, pbin_ref, cnt_ref, rows_ref, *, n_frames,
                  threshold_db, hysteresis_db, min_len, capacity):
-    count, rows = scan_events(
-        spl_ref[...], pbin_ref[...], n_frames=n_frames,
+    """One record block.  The refs hold (n_tiles, block, LANES) tiles:
+    frame ``f`` of every record sits in lane ``f % LANES`` of tile
+    ``f // LANES``.  A frame is read as a dynamic tile load plus a
+    one-lane select-and-max — exact, since every other lane is the
+    reduction's identity — because the TPU lowering has no dynamic
+    slice along lanes."""
+    n_tiles, b, _ = spl_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (b, LANES), 1)
+
+    def read(f):
+        hit = lane == f % LANES
+        t = f // LANES
+        s = jnp.max(jnp.where(hit, spl_ref[t], -jnp.inf), axis=1,
+                    keepdims=True)
+        pb = jnp.max(jnp.where(hit, pbin_ref[t], jnp.iinfo(jnp.int32).min),
+                     axis=1, keepdims=True)
+        return s, pb
+
+    count, cols = scan_events(
+        read, b=b, f_total=n_tiles * LANES, n_frames=n_frames,
         threshold_db=threshold_db, hysteresis_db=hysteresis_db,
         min_len=min_len, capacity=capacity)
-    cnt_ref[...] = count[:, None]
-    rows_ref[...] = rows
+    cnt_ref[...] = count
+    for c, col in enumerate(cols):
+        rows_ref[c] = col
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -155,13 +191,17 @@ def detect_events(spl: jnp.ndarray, peak_bin: jnp.ndarray, *,
     bpad = common.round_up(max(n_rec, 1), block_records)
     # frames padded to the lane width with -inf: closes edge events at
     # the true record end, never opens one
-    fpad = common.round_up(n_frames, 128)
+    fpad = common.round_up(n_frames, LANES)
     spl = jnp.pad(spl.astype(jnp.float32),
                   ((0, bpad - n_rec), (0, fpad - n_frames)),
                   constant_values=-jnp.inf)
     peak_bin = jnp.pad(peak_bin.astype(jnp.int32),
                        ((0, bpad - n_rec), (0, fpad - n_frames)))
 
+    def tiles(x):          # (bpad, fpad) -> (fpad // LANES, bpad, LANES)
+        return x.reshape(bpad, fpad // LANES, LANES).transpose(1, 0, 2)
+
+    n_tiles = fpad // LANES
     body = functools.partial(
         _events_body, n_frames=n_frames, threshold_db=threshold_db,
         hysteresis_db=hysteresis_db, min_len=min_len, capacity=capacity)
@@ -169,19 +209,22 @@ def detect_events(spl: jnp.ndarray, peak_bin: jnp.ndarray, *,
         body,
         grid=(bpad // block_records,),
         in_specs=[
-            pl.BlockSpec((block_records, fpad), lambda i: (i, 0)),
-            pl.BlockSpec((block_records, fpad), lambda i: (i, 0)),
+            pl.BlockSpec((n_tiles, block_records, LANES),
+                         lambda i: (0, i, 0)),
+            pl.BlockSpec((n_tiles, block_records, LANES),
+                         lambda i: (0, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_records, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_records, capacity, N_EVENT_COLS),
-                         lambda i: (i, 0, 0)),
+            pl.BlockSpec((N_EVENT_COLS, block_records, capacity),
+                         lambda i: (0, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bpad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((bpad, capacity, N_EVENT_COLS),
+            jax.ShapeDtypeStruct((N_EVENT_COLS, bpad, capacity),
                                  jnp.float32),
         ],
         interpret=interpret,
-    )(spl, peak_bin)
-    return counts[:n_rec, 0], rows[:n_rec]
+        name="detect_events",
+    )(tiles(spl), tiles(peak_bin))
+    return counts[:n_rec, 0], jnp.moveaxis(rows, 0, -1)[:n_rec]

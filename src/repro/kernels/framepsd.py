@@ -196,6 +196,7 @@ def frame_psd(x: jnp.ndarray, p, block_frames: int = 256,
                                lambda i, k: (i, k)),
         out_shape=jax.ShapeDtypeStruct((fpad, bpad), jnp.float32),
         interpret=interpret,
+        name="frame_psd",
     )(*operands)
 
     out = out[:total_frames, : p.n_bins]
@@ -209,16 +210,20 @@ def frame_psd(x: jnp.ndarray, p, block_frames: int = 256,
 # ----------------------------------------------------------------------
 
 def _welch_update(view, c_ref, s_ref, scale_ref, o_ref, *, m: int):
-    """One frame-chunk's contribution to the per-record Welch mean."""
+    """One frame-chunk's contribution to the per-record Welch mean.
+
+    ``o_ref`` is the record's (1, block_bins) row of the (n_rec, 1,
+    bpad) output: a singleton middle axis, so the block spans whole
+    trailing axes as the TPU lowering requires."""
     f = pl.program_id(2)
 
     @pl.when(f == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
     acc_r, acc_i = _dft_accum(view, c_ref, s_ref, m=m)
     psd = acc_r * acc_r + acc_i * acc_i            # (chunk_frames, bins)
-    o_ref[...] += jnp.sum(psd, axis=0, keepdims=True) * scale_ref[0, :]
+    o_ref[0] += jnp.sum(psd, axis=0, keepdims=True) * scale_ref[0, :]
 
 
 def _welch_body(v_ref, c_ref, s_ref, scale_ref, o_ref, *, m: int):
@@ -227,10 +232,10 @@ def _welch_body(v_ref, c_ref, s_ref, scale_ref, o_ref, *, m: int):
 
 
 def _welch_body_q(v_ref, q_ref, c_ref, s_ref, scale_ref, o_ref, *, m: int):
-    """int16 variant: one decode scale per record (``q_ref`` (1, 1)),
+    """int16 variant: one decode scale per record (``q_ref`` (1, 1, 1)),
     applied to the samples before the matmul chain — same rounding
     order as the host decode, so the fused Welch stays bitwise-equal."""
-    q = q_ref[0, 0]
+    q = q_ref[0, 0, 0]
     _welch_update(lambda r: v_ref[r, 0].astype(jnp.float32) * q,
                   c_ref, s_ref, scale_ref, o_ref, m=m)
 
@@ -279,20 +284,27 @@ def welch_psd(records: jnp.ndarray, p, chunk_frames: int = 512,
     body = functools.partial(_welch_body, m=m)
     if quantized:
         if scales is None:
-            sq = jnp.full((n_rec, 1), common.PCM_DECODE_SCALE, jnp.float32)
+            sq = jnp.full((n_rec, 1, 1), common.PCM_DECODE_SCALE,
+                          jnp.float32)
         else:
-            sq = jnp.asarray(scales, jnp.float32).reshape(n_rec, 1)
-        in_specs.insert(1, pl.BlockSpec((1, 1), lambda r, k, f: (r, 0)))
+            sq = jnp.asarray(scales, jnp.float32).reshape(n_rec, 1, 1)
+        in_specs.insert(1, pl.BlockSpec((1, 1, 1),
+                                        lambda r, k, f: (r, 0, 0)))
         operands.insert(1, sq)
         body = functools.partial(_welch_body_q, m=m)
 
+    # per-record blocks carry a singleton middle axis: a (1, block)
+    # block of an (n_rec, bpad) array is refused by the TPU lowering
+    # (second-minor block dim neither a multiple of 8 nor the whole axis)
     out = pl.pallas_call(
         body,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_bins), lambda r, k, f: (r, k)),
-        out_shape=jax.ShapeDtypeStruct((n_rec, bpad), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_bins),
+                               lambda r, k, f: (r, 0, k)),
+        out_shape=jax.ShapeDtypeStruct((n_rec, 1, bpad), jnp.float32),
         interpret=interpret,
+        name="welch_psd",
     )(*operands)
 
-    return out[:, : p.n_bins]
+    return out[:, 0, : p.n_bins]

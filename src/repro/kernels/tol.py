@@ -55,5 +55,6 @@ def tol_levels(psd: jnp.ndarray, band_matrix: jnp.ndarray, p,
         out_specs=pl.BlockSpec((block_records, gpad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rpad, gpad), jnp.float32),
         interpret=interpret,
+        name="tol_levels",
     )(x, jnp.asarray(m))
     return out[:n_rec, :n_bands]

@@ -55,5 +55,6 @@ def welch_mean(frame_psd: jnp.ndarray, block_records: int = 8,
                                lambda r, k, f: (r, k)),
         out_shape=jax.ShapeDtypeStruct((rpad, bpad), jnp.float32),
         interpret=interpret,
+        name="welch_mean",
     )(x.astype(jnp.float32))
     return out[:n_rec, :n_bins]

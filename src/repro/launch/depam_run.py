@@ -93,6 +93,7 @@ from repro import api
 from repro.core.manifest import DatasetManifest
 from repro.core.params import PARAM_SET_1, PARAM_SET_2
 from repro.core.store import FeatureStore
+from repro.launch import runtime
 
 
 def print_feature_list(m, p) -> None:
@@ -146,7 +147,7 @@ def parse_instrument(arg: str):
         raise SystemExit(f"--instrument: {e}")
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> None:
     # app-level choice (deliberately not made by the library): the
     # engine donates payload buffers for the early free; the jax
     # "donation was not usable" diagnostic is noise for this CLI
@@ -237,7 +238,7 @@ def main() -> None:
                          "scans: 'auto' (builtin PAM conventions), "
                          "'none', a strptime pattern, or a regex with "
                          "named groups")
-    a = ap.parse_args()
+    a = ap.parse_args(argv)
 
     base = PARAM_SET_1 if a.param_set == 1 else PARAM_SET_2
     p = base if a.record_sec is None else dataclasses.replace(
@@ -267,6 +268,7 @@ def main() -> None:
                             records_per_file=a.records_per_file,
                             record_size=p.record_size, fs=p.fs, seed=42)
     feats = [f.strip() for f in a.features.split(",") if f.strip()]
+    print(f"[depam] {runtime.device_line()}")
     print(f"[depam] param set {a.param_set} (nfft={p.nfft}, "
           f"overlap={p.window_overlap}); dataset {m.n_records} records "
           f"({m.total_gb:.3f} GB); features {feats}")
@@ -403,4 +405,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    runtime.enable_compile_cache()
     main()

@@ -6,6 +6,7 @@ device state (the dry-run sets XLA_FLAGS before any jax initialization).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,16 +16,16 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(model: int = 1, data: int | None = None):
-    """Mesh over host devices (CPU smoke / tiny CI meshes).
+    """Mesh over the visible devices (the chips of one host, or forced
+    host-platform CPU devices in tests).
 
     Default: all visible devices, split ``(data=n//model, model)``.
     With ``data=``: a submesh over the FIRST ``data * model`` devices —
     how a scaling sweep runs the same job at 1, 2, 4, ... data shards
-    inside one process without re-initializing jax.
+    inside one process without re-initializing jax.  Either way the
+    axes are ``Auto``: the engine's step is a ``shard_map`` over the
+    data axes, and its reduce update is partitioned by XLA.
     """
-    import numpy as np
-    from jax.sharding import Mesh
-
     devs = jax.devices()
     n = len(devs)
     if model < 1 or (data is None and n % model != 0):
@@ -33,16 +34,16 @@ def make_host_mesh(model: int = 1, data: int | None = None):
             f"device(s) cannot form a (data={n}//{max(model, 1)}, "
             f"model={model}) mesh — device count must be a positive "
             f"multiple of `model`")
-    if data is None:
-        return jax.make_mesh((n // model, model), ("data", "model"))
-    want = int(data) * model
+    data = n // model if data is None else int(data)
+    want = data * model
     if data < 1 or want > n:
         raise ValueError(
             f"make_host_mesh(model={model}, data={data}): requested a "
             f"(data={data}, model={model}) mesh = {want} device(s) but "
             f"only {n} visible")
-    grid = np.asarray(devs[:want]).reshape(int(data), model)
-    return Mesh(grid, ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto),
+                         devices=devs[:want])
 
 
 def data_axes(mesh) -> tuple[str, ...]:

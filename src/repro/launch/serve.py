@@ -40,6 +40,7 @@ import numpy as np
 from repro import api
 from repro.core.manifest import DatasetManifest
 from repro.core.params import PARAM_SET_1, PARAM_SET_2
+from repro.launch import runtime
 from repro.serve import (DeficitRoundRobin, LiveSource, RoundRobin,
                          SoundscapeService)
 
@@ -99,6 +100,7 @@ def run(tenants: int = 2, live: int = 0, files: int = 2,
             1275566400.0 + i * span for i in range(files)))
     sched = DeficitRoundRobin() if scheduler == "drr" else RoundRobin()
     svc = SoundscapeService(scheduler=sched, quantum=quantum)
+    print(f"[serve] {runtime.device_line()}")
     print(f"[serve] {tenants} batch + {live} live tenants over one "
           f"device; dataset {m.n_records} records x "
           f"{p.record_size} samples; features {list(features)}; "
@@ -193,7 +195,7 @@ def run(tenants: int = 2, live: int = 0, files: int = 2,
     return results, svc
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> None:
     warnings.filterwarnings(
         "ignore", message="Some donated buffers were not usable")
     ap = argparse.ArgumentParser()
@@ -224,7 +226,7 @@ def main() -> None:
     ap.add_argument("--verify", action="store_true",
                     help="re-run each tenant solo and assert the "
                          "concurrent results are bitwise-identical")
-    a = ap.parse_args()
+    a = ap.parse_args(argv)
     weights = [float(w) for w in a.weights.split(",")] \
         if a.weights else None
     run(tenants=a.tenants, live=a.live, files=a.files,
@@ -237,4 +239,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    runtime.enable_compile_cache()
     main()

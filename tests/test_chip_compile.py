@@ -1,0 +1,184 @@
+"""Compile the main-path kernels and the jitted step for a TPU v5e.
+
+Nothing runs: the TPU compiler, which is installed with jax, compiles
+for a described ``v5e:2x2`` topology at the paper's widths (fs 32,768
+Hz; set 1 with 60 s records, set 2 with 10 s records).  These are the
+refusals interpret mode cannot show: block shapes the TPU lowering
+rejects, primitives Mosaic cannot lower, and kernels XLA cannot
+partition over a mesh.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every pytest worker
+imports this file.  Interpret mode is switched off per test with
+``monkeypatch``, and the jit caches are cleared on both sides so no
+TPU-lowered trace leaks into a later CPU test (or the reverse).
+"""
+import collections
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (AxisType, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.api import engine, resolve_features
+from repro.core.manifest import DatasetManifest
+from repro.core.params import PARAM_SET_1, PARAM_SET_2
+from repro.core.tol import band_matrix
+from repro.kernels import common, ct_rfft, events, framepsd, tol
+
+RECORDS = 8
+# every feature of the smoke job; events adds the impulsive metrics
+FEATURES = ("welch", "spl", "tol", "percentiles", "ltsa", "spd", "minmax",
+            "events", "impulsive")
+KERNELS = {1: {"welch_psd", "frame_psd", "tol_levels", "detect_events"},
+           2: {"ct_frame_psd", "welch_mean", "tol_levels",
+               "detect_events"}}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                           # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,),
+                         devices=topo.devices)
+
+
+@pytest.fixture
+def compiled_for_tpu(monkeypatch):
+    """Kernels lower through Mosaic, not the interpreter."""
+    jax.clear_caches()
+    engine.compile_step.cache_clear()
+    monkeypatch.setattr(common, "use_interpret", lambda: False)
+    yield
+    jax.clear_caches()
+    engine.compile_step.cache_clear()
+
+
+def folded_constants(hlo_text: str) -> collections.Counter:
+    """(dtype, literal) of every constant in a compiled program."""
+    return collections.Counter(re.findall(
+        r"= (\w+)\[[^\]]*\][^ ]* constant\((.*?)\)(?:, metadata|$)",
+        hlo_text, re.M))
+
+
+def compile_kernels(fn, *shapes) -> collections.Counter:
+    return common.tpu_kernel_calls(
+        jax.jit(fn).lower(*shapes).compile().as_text())
+
+
+def spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.usefixtures("compiled_for_tpu")
+class TestKernelsCompileForV5e:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int16],
+                             ids=["float32", "int16"])
+    def test_welch_psd_set1(self, one_chip, dtype):
+        p = PARAM_SET_1
+        x = spec(one_chip, (RECORDS, p.record_size), dtype)
+        q = spec(one_chip, (RECORDS,), jnp.float32)
+        calls = compile_kernels(
+            lambda x, q: framepsd.welch_psd(
+                x, p, scales=q if dtype == jnp.int16 else None), x, q)
+        assert calls == {"welch_psd": 1}
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int16],
+                             ids=["float32", "int16"])
+    def test_ct_frame_psd_set2(self, one_chip, dtype):
+        p = PARAM_SET_2
+        nf = RECORDS * p.frames_per_record
+        x = spec(one_chip, (nf, p.window_size), dtype)
+        q = spec(one_chip, (nf,), jnp.float32)
+        calls = compile_kernels(
+            lambda x, q: ct_rfft.ct_frame_psd(
+                x, p, scales=q if dtype == jnp.int16 else None), x, q)
+        assert calls == {"ct_frame_psd": 1}
+
+    def test_frame_psd_set1(self, one_chip):
+        p = PARAM_SET_1
+        assert p.frames_per_record == 15359
+        x = spec(one_chip, (RECORDS, p.record_size), jnp.int16)
+        q = spec(one_chip, (RECORDS,), jnp.float32)
+        calls = compile_kernels(
+            lambda x, q: framepsd.frame_psd(x, p, scales=q), x, q)
+        assert calls == {"frame_psd": 1}
+
+    def test_tol_levels_set1(self, one_chip):
+        p = PARAM_SET_1
+        bm = jnp.asarray(band_matrix(p))
+        psd = spec(one_chip, (RECORDS, p.n_bins), jnp.float32)
+        calls = compile_kernels(lambda s: tol.tol_levels(s, bm, p), psd)
+        assert calls == {"tol_levels": 1}
+
+    def test_detect_events_set1(self, one_chip):
+        p = PARAM_SET_1
+        shape = (RECORDS, p.frames_per_record)
+        calls = compile_kernels(
+            lambda s, b: events.detect_events(
+                s, b, threshold_db=p.event_threshold_db,
+                hysteresis_db=p.event_hysteresis_db,
+                capacity=p.event_capacity),
+            spec(one_chip, shape, jnp.float32),
+            spec(one_chip, shape, jnp.int32))
+        assert calls == {"detect_events": 1}
+
+
+def compile_step(p, sharding, mesh=None, n_shards=1, chunk=4):
+    """The engine's int16-transport step for every smoke feature, over
+    two 45-minute files."""
+    m = DatasetManifest(n_files=2,
+                        records_per_file=int(45 * 60 // p.record_size_sec),
+                        record_size=p.record_size, fs=p.fs, seed=42)
+    step = engine.compile_step(tuple(resolve_features(FEATURES)), m, p,
+                               mesh, ("data",), True, False, True, "int16")
+    lead = (n_shards, chunk)
+    return step.lower(spec(sharding, lead + (p.record_size,), jnp.int16),
+                      spec(sharding, lead, jnp.float32),
+                      spec(sharding, lead, jnp.bool_)).compile()
+
+
+@pytest.mark.usefixtures("compiled_for_tpu")
+class TestStepCompilesForV5e:
+    @pytest.mark.parametrize("param_set", [1, 2])
+    def test_one_chip(self, one_chip, param_set):
+        p = PARAM_SET_1 if param_set == 1 else PARAM_SET_2
+        compiled = compile_step(p, one_chip)
+        calls = common.tpu_kernel_calls(compiled.as_text())
+        assert calls == dict.fromkeys(KERNELS[param_set], 1)
+        # fits a 16 GB chip with room for the pipeline's in-flight steps
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+    def test_four_chip_mesh(self, one_chip, four_chips):
+        """The step is a shard_map: every device runs each kernel once
+        on its own shard slice, and no collective moves the payload.
+        It folds the same constants as the one-chip step — the two
+        compilations once folded a percentile weight differently, which
+        broke bitwise equality across device counts on the chip."""
+        sharding = NamedSharding(four_chips, P(("data",)))
+        text = compile_step(PARAM_SET_1, sharding, mesh=four_chips,
+                            n_shards=4, chunk=2).as_text()
+        single = compile_step(PARAM_SET_1, one_chip, chunk=2).as_text()
+        assert folded_constants(text) == folded_constants(single)
+        assert common.tpu_kernel_calls(text) == dict.fromkeys(KERNELS[1], 1)
+        for op in ("all-gather", "all-reduce", "all-to-all",
+                   "collective-permute", "reduce-scatter"):
+            assert op not in text, op
+        # each device's parameter is its own (1, chunk, record) slice
+        assert f"s16[1,2,{PARAM_SET_1.record_size}]" in text

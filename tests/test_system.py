@@ -83,7 +83,9 @@ print("SHARDED-OK")
         env = dict(os.environ)
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                          "src")
-        env.pop("JAX_PLATFORMS", None)
+        # the forced host devices are CPU devices; on a machine with an
+        # accelerator the child must not try to take it
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run([sys.executable, "-c", code], env=env,
                              capture_output=True, text=True, timeout=900)
         assert "SHARDED-OK" in out.stdout, out.stderr[-2000:]
