@@ -58,6 +58,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.manifest import DatasetManifest, ShardPlan
+from repro.core import spans
 from repro.core.params import DepamParams
 from repro.distributed import partition as partition_lib
 from .features import (EPOCH_WINDOW, FeatureContext, FeatureSpec,
@@ -512,6 +513,7 @@ class JobStepper:
         self._overflowed = False     # event-capacity warning fired once
 
     # -- lifecycle ------------------------------------------------------
+    @spans.spanned("start")
     def start(self) -> "JobStepper":
         """Bind, compile, open the sink, restore committed state.
 
@@ -695,48 +697,51 @@ class JobStepper:
         if not source.device_synth:
             # fetch BEFORE freezing the mask: a tolerant source may
             # quarantine records of this very step while reading them
-            payload = np.asarray(next(self._stream))
-        if self.quarantine is not None and len(self.quarantine):
-            # quarantined records carry zero payloads; masking them
-            # keeps them out of every reduction and leaves their rows
-            # at the feature's fill value — reduction identities, never
-            # a silently-wrong number
-            mask = mask & ~self.quarantine.mask_for(idx)
-        dmask = jnp.asarray(mask)
-        wids = {k: jnp.asarray(w.ids(idx, self.m))
-                for k, w in self._wins.items()}
-        if source.device_synth:
-            out = self._step_fn(self._ship(np.asarray(idx, np.int32)),
-                                dmask)
-        elif self._raw:
-            # raw-PCM transport: ship the int16 bytes as-is (half the
-            # bus traffic, still donated) + the tiny per-record
-            # decode-scale sidecar; kernels dequantize in VMEM
-            if payload.dtype != np.int16:
-                raise TypeError(
-                    f"int16 payload path got {payload.dtype} from "
-                    f"{type(source).__name__}.stream — the source's "
-                    f"payload_dtype promises raw '<i2' PCM")
-            out = self._step_fn(self._ship(payload),
-                                jnp.asarray(source.scales(idx),
-                                            jnp.float32),
-                                dmask)
-        else:
-            out = self._step_fn(self._ship(payload.astype(np.float32,
-                                                          copy=False)),
-                                dmask)
-        self._agg_state = self._agg_fn(self._agg_state, out, dmask, wids)
-        # start the device→host transfers now; block in _drain_one —
-        # reduction-only values never cross back to the host
-        for name in self._shapes:
-            out[name].copy_to_host_async()
-        for name in self._ragged:
-            out[name]["counts"].copy_to_host_async()
-            out[name]["rows"].copy_to_host_async()
-        commit_state = self._agg_state if self.sink.wants_commit else None
-        if commit_state is not None:
-            for v in commit_state.values():
-                v.copy_to_host_async()
+            with spans.span("fetch_wait", step=step, records=idx.size):
+                payload = np.asarray(next(self._stream))
+        with spans.span("dispatch", step=step) as sp:
+            if self.quarantine is not None and len(self.quarantine):
+                # quarantined records carry zero payloads; masking them
+                # keeps them out of every reduction and leaves their
+                # rows at the feature's fill value — reduction
+                # identities, never a silently-wrong number
+                mask = mask & ~self.quarantine.mask_for(idx)
+            dmask = jnp.asarray(mask)
+            wids = {k: jnp.asarray(w.ids(idx, self.m))
+                    for k, w in self._wins.items()}
+            if source.device_synth:
+                shipped = (self._ship(np.asarray(idx, np.int32)),)
+            elif self._raw:
+                # raw-PCM transport: ship the int16 bytes as-is (half
+                # the bus traffic, still donated) + the tiny per-record
+                # decode-scale sidecar; kernels dequantize in VMEM
+                if payload.dtype != np.int16:
+                    raise TypeError(
+                        f"int16 payload path got {payload.dtype} from "
+                        f"{type(source).__name__}.stream — the source's "
+                        f"payload_dtype promises raw '<i2' PCM")
+                shipped = (self._ship(payload),
+                           jnp.asarray(source.scales(idx), jnp.float32))
+            else:
+                shipped = (self._ship(payload.astype(np.float32,
+                                                     copy=False)),)
+            sp.set_metadata(h2d_bytes=sum(
+                x.nbytes for x in (*shipped, dmask, *wids.values())))
+            out = self._step_fn(*shipped, dmask)
+            self._agg_state = self._agg_fn(self._agg_state, out, dmask,
+                                           wids)
+            # start the device→host transfers now; block in _drain_one
+            # — reduction-only values never cross back to the host
+            for name in self._shapes:
+                out[name].copy_to_host_async()
+            for name in self._ragged:
+                out[name]["counts"].copy_to_host_async()
+                out[name]["rows"].copy_to_host_async()
+            commit_state = self._agg_state if self.sink.wants_commit \
+                else None
+            if commit_state is not None:
+                for v in commit_state.values():
+                    v.copy_to_host_async()
         self._inflight.append((step, idx, mask, out, commit_state))
         self._step += 1
         while len(self._inflight) > self.options.inflight:
@@ -744,73 +749,88 @@ class JobStepper:
         return True
 
     # -- sink side ------------------------------------------------------
-    def _flush_closed(self, commit_state, cursor):
+    def _flush_closed(self, step, commit_state, cursor):
         """Finalize + write every window the cursor just closed, BEFORE
         the commit that makes the cursor durable covers them."""
-        for b in self._windowed:
-            closed = _closed_windows(self._edges[b.out_name], cursor)
-            if closed > self._flushed[b.out_name]:
-                rows = _finalize_rows(
-                    b, commit_state, self._flushed[b.out_name], closed)
-                self.sink.write_windows(b.out_name,
-                                        self._flushed[b.out_name],
-                                        rows.astype(np.float32))
-                self._flushed[b.out_name] = closed
+        with spans.span("flush_windows", step=step):
+            for b in self._windowed:
+                closed = _closed_windows(self._edges[b.out_name], cursor)
+                if closed > self._flushed[b.out_name]:
+                    rows = _finalize_rows(
+                        b, commit_state, self._flushed[b.out_name], closed)
+                    with spans.span("sink_put", step=step):
+                        self.sink.write_windows(b.out_name,
+                                                self._flushed[b.out_name],
+                                                rows.astype(np.float32))
+                    self._flushed[b.out_name] = closed
 
     def _drain_one(self):
         """Materialize the oldest in-flight step into the sink."""
         step, idx, mask, out, commit_state = self._inflight.popleft()
-        flat_idx = idx.reshape(-1)
+        with spans.span("drain", step=step):
+            self._drain(step, idx, mask, out, commit_state)
+
+    def _drain(self, step, idx, mask, out, commit_state):
         keep = mask.reshape(-1)
-        sel = flat_idx[keep]
-        values = {
-            name: np.asarray(out[name]).reshape(
-                (-1,) + self._shapes[name])[keep]
-            for name in self._shapes}
-        self.sink.write(step, sel, values)
+        sel = idx.reshape(-1)[keep]
+        # carry persisted in its NATIVE dtypes (float32 / int32): resume
+        # casts losslessly, _finalize_rows widens to float64 itself, and
+        # the commit sidecar stays state-sized
+        carry = {} if commit_state is None else commit_state
+        pulled = [out[name] for name in self._shapes] \
+            + [out[name][k] for name in self._ragged
+               for k in ("counts", "rows")] + list(carry.values())
+        with spans.span("d2h_wait", step=step,
+                        d2h_bytes=sum(x.nbytes for x in pulled)):
+            values = {name: np.asarray(out[name]) for name in self._shapes}
+            slabs = {name: (np.asarray(out[name]["counts"]),
+                            np.asarray(out[name]["rows"]))
+                     for name in self._ragged}
+            agg_host = {k: np.asarray(v) for k, v in carry.items()}
+        values = {name: v.reshape((-1,) + self._shapes[name])[keep]
+                  for name, v in values.items()}
+        with spans.span("sink_put", step=step):
+            self.sink.write(step, sel, values)
         if self._ragged:
             # host-side compaction: the device returned fixed-capacity
             # slabs; only the first min(count, capacity) rows of each
             # live record enter the append-only log (record order —
             # boolean take over (batch, capacity) preserves it)
-            ev = {}
-            for name in self._ragged:
-                counts = np.asarray(
-                    out[name]["counts"]).reshape(-1)[keep]
-                rows = np.asarray(out[name]["rows"])
-                rows = rows.reshape((-1,) + rows.shape[-2:])[keep]
-                cap = rows.shape[1]
-                slot = np.arange(cap)[None, :] < \
-                    np.minimum(counts, cap)[:, None]
-                ev[name] = (counts.astype(np.int32),
-                            rows[slot].astype(np.float32, copy=False))
-                if not self._overflowed and (counts > cap).any():
-                    self._overflowed = True
-                    import warnings
-                    warnings.warn(
-                        f"event capacity overflow in feature {name!r}: "
-                        f"some records detected more than {cap} events; "
-                        f"only the first {cap} are kept (raise "
-                        f"DepamParams.event_capacity or the threshold). "
-                        f"Affected records have counts > capacity in "
-                        f"the event log.", RuntimeWarning, stacklevel=2)
-            self.sink.write_events(step, sel, ev)
+            with spans.span("compact", step=step) as sp:
+                ev = {}
+                for name, (counts, rows) in slabs.items():
+                    counts = counts.reshape(-1)[keep]
+                    rows = rows.reshape((-1,) + rows.shape[-2:])[keep]
+                    cap = rows.shape[1]
+                    slot = np.arange(cap)[None, :] < \
+                        np.minimum(counts, cap)[:, None]
+                    ev[name] = (counts.astype(np.int32),
+                                rows[slot].astype(np.float32, copy=False))
+                    if not self._overflowed and (counts > cap).any():
+                        self._overflowed = True
+                        import warnings
+                        warnings.warn(
+                            f"event capacity overflow in feature "
+                            f"{name!r}: some records detected more than "
+                            f"{cap} events; only the first {cap} are kept "
+                            f"(raise DepamParams.event_capacity or the "
+                            f"threshold). Affected records have counts > "
+                            f"capacity in the event log.", RuntimeWarning,
+                            stacklevel=3)
+                sp.set_metadata(events=sum(len(r) for _, r in ev.values()))
+            with spans.span("sink_put", step=step):
+                self.sink.write_events(step, sel, ev)
         if commit_state is not None:
-            # carry persisted in its NATIVE dtypes (float32 / int32):
-            # resume casts losslessly, _finalize_rows widens to float64
-            # itself, and the commit sidecar stays state-sized
-            agg_host = {k: np.asarray(v)
-                        for k, v in commit_state.items()
-                        if k != "__live__"}
+            live = float(agg_host.pop("__live__"))
             if self.quarantine is not None:
                 # snapshot of the bad-record set rides the commit as an
                 # opaque key (bad records are deterministic-by-record,
                 # so a snapshot that is "ahead" of this step's cursor
                 # only pre-masks records that would re-fail anyway)
                 agg_host["__quarantine__"] = self.quarantine.as_array()
-            self._flush_closed(agg_host, self.pl.cursor_after(step))
-            self.sink.commit(self.pl, step, agg_host,
-                             float(commit_state["__live__"]))
+            self._flush_closed(step, agg_host, self.pl.cursor_after(step))
+            with spans.span("sink_put", step=step):
+                self.sink.commit(self.pl, step, agg_host, live)
 
     def finish(self):
         """Drain the pipeline, finalize every window (trailing partial
@@ -831,14 +851,18 @@ class JobStepper:
         while self._inflight:
             self._drain_one()
         host_state = {k: np.asarray(v) for k, v in self._agg_state.items()}
-        for b in self._windowed:
-            rows = _finalize_rows(b, host_state, 0, b.n_windows)
-            self._windows_out[b.out_name] = rows.astype(np.float32)
-            if self._flushed[b.out_name] < b.n_windows:
-                self.sink.write_windows(
-                    b.out_name, self._flushed[b.out_name],
-                    self._windows_out[b.out_name][self._flushed[b.out_name]:])
-                self._flushed[b.out_name] = b.n_windows
+        last = self._step - 1
+        with spans.span("flush_windows", step=last):
+            for b in self._windowed:
+                rows = _finalize_rows(b, host_state, 0, b.n_windows)
+                self._windows_out[b.out_name] = rows.astype(np.float32)
+                start = self._flushed[b.out_name]
+                if start < b.n_windows:
+                    with spans.span("sink_put", step=last):
+                        self.sink.write_windows(
+                            b.out_name, start,
+                            self._windows_out[b.out_name][start:])
+                    self._flushed[b.out_name] = b.n_windows
 
         live = int(host_state["__live__"])
         epoch = {}

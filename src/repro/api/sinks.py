@@ -55,6 +55,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.manifest import DatasetManifest, ShardPlan
 from repro.core.params import DepamParams
 from repro.core.store import FeatureStore
@@ -499,6 +500,7 @@ class AsyncSink(Sink):
         self._worker: threading.Thread | None = None
         self._error: BaseException | None = None
         self._killed = False
+        self._step = -1              # step of the last queued write
 
     # -- worker ---------------------------------------------------------
     def _run(self):
@@ -509,16 +511,10 @@ class AsyncSink(Sink):
                     return
                 if self._killed or self._error is not None:
                     continue          # drain without executing
-                op, args = item
+                op, step, args = item
                 try:
-                    if op == "write":
-                        self.inner.write(*args)
-                    elif op == "windows":
-                        self.inner.write_windows(*args)
-                    elif op == "events":
-                        self.inner.write_events(*args)
-                    else:
-                        self.inner.commit(*args)
+                    with spans.span("sink." + op, step=step):
+                        getattr(self.inner, op)(*args)
                 except BaseException as e:     # noqa: BLE001
                     self._error = e
             finally:
@@ -576,14 +572,16 @@ class AsyncSink(Sink):
     # -- queued data plane ----------------------------------------------
     def write(self, step, indices, values):
         self._raise_pending()
-        self._q.put(("write", (step, indices, values)))
+        self._step = step
+        self._q.put(("write", step, (step, indices, values)))
 
     def write_windows(self, name, start, values):
         # rides the same FIFO, so a window row always lands before the
         # commit that makes its cursor durable — crash semantics
-        # identical to the synchronous path
+        # identical to the synchronous path; its span carries the step
+        # of the last write, the drain that flushed it
         self._raise_pending()
-        self._q.put(("windows", (name, start, values)))
+        self._q.put(("write_windows", self._step, (name, start, values)))
 
     def write_events(self, step, indices, values):
         # FIFO again: the store's append position at commit(step=k)
@@ -591,11 +589,11 @@ class AsyncSink(Sink):
         # commit records can never cover an unwritten (or skip a
         # written) event
         self._raise_pending()
-        self._q.put(("events", (step, indices, values)))
+        self._q.put(("write_events", step, (step, indices, values)))
 
     def commit(self, plan, step, agg, live):
         self._raise_pending()
-        self._q.put(("commit", (plan, step, agg, live)))
+        self._q.put(("commit", step, (plan, step, agg, live)))
 
     def flush(self):
         """Block until every queued write/commit has been applied."""

@@ -351,7 +351,6 @@ class PrefetchSource(Source):
         self.overdecompose = overdecompose
         self.speculate_factor = speculate_factor
         self.min_speculate_sec = min_speculate_sec
-        self.last_stats: dict | None = None
         self._manifest: DatasetManifest | None = None
 
     @property
@@ -413,7 +412,6 @@ class PrefetchSource(Source):
             for _step, payload, _mask in loader.iter_steps(start, stop):
                 yield payload
         finally:
-            self.last_stats = loader.stats()
             loader.close()
 
 

@@ -24,9 +24,15 @@ import numpy as np
 
 from repro.faults.errors import StoreIntegrityError
 
+from . import spans
 from .manifest import DatasetManifest, ShardPlan
 from .params import DepamParams
 from .tol import band_matrix as make_band_matrix
+
+
+def _fsync(f) -> None:
+    with spans.span("store.fsync"):
+        os.fsync(f.fileno())
 
 
 class FeatureStore:
@@ -286,6 +292,11 @@ class FeatureStore:
         between the two leaves an orphan sidecar (garbage-collected on
         the next commit), never a torn pair.
         """
+        with spans.span("store.commit", step=step) as sp:
+            sp.set_metadata(bytes=self._commit_state(plan, step, agg, live))
+
+    def _commit_state(self, plan, step, agg, live) -> int:
+        """``commit_state``'s protocol; returns the sidecar's bytes."""
         if self._arrays:
             for a in self._arrays.values():
                 a.flush()
@@ -324,7 +335,7 @@ class FeatureStore:
             for ev in self._events.values():
                 ev["counts"].flush()
                 ev["file"].flush()
-                os.fsync(ev["file"].fileno())
+                _fsync(ev["file"])
             state["events"] = {name: ev["rows"]
                                for name, ev in self._events.items()}
             # running CRC32 of each log's committed prefix; open_events
@@ -342,6 +353,7 @@ class FeatureStore:
                 state["events"] = prev["events"]
                 if "events_crc" in prev:
                     state["events_crc"] = prev["events_crc"]
+        payload = b""
         if agg:
             # serialize in memory first so the CRC32 committed in the
             # cursor covers exactly the bytes renamed in — load_agg
@@ -355,7 +367,7 @@ class FeatureStore:
             with open(tmp, "wb") as f:
                 f.write(payload)
                 f.flush()
-                os.fsync(f.fileno())
+                _fsync(f)
             os.replace(tmp, os.path.join(self.root, fname))
             state["agg_file"] = fname
             state["agg_crc"] = zlib.crc32(payload)
@@ -368,7 +380,7 @@ class FeatureStore:
         with open(tmp, "w") as f:
             json.dump(state, f)
             f.flush()
-            os.fsync(f.fileno())
+            _fsync(f)
         if self.faults is not None:
             # cursor tmp is durable but not renamed in: resume must
             # ignore it entirely
@@ -381,6 +393,7 @@ class FeatureStore:
                     os.remove(os.path.join(self.root, name))
                 except OSError:
                     pass
+        return len(payload)
 
     def commit(self, plan: ShardPlan, step: int, welch_sum: np.ndarray,
                live: float) -> None:

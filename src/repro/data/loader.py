@@ -36,6 +36,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.manifest import ShardPlan
 from repro.faults.errors import is_retryable
 
@@ -79,8 +80,10 @@ class SpeculativeLoader:
 
     # -- one read task (leaf work, runs on read_pool) -------------------
     def _timed_read(self, idx: np.ndarray) -> np.ndarray:
-        t0 = time.monotonic()
-        out = self.reader(idx)
+        with spans.span("read", records=idx.size) as sp:
+            t0 = time.monotonic()
+            out = self.reader(idx)
+            sp.set_metadata(bytes=out.nbytes)
         with self._lock:
             self.durations.append(time.monotonic() - t0)
         return out

@@ -258,9 +258,9 @@ class TestPrefetchSource:
         got = list(pre.stream(pl_, 1, pl_.n_steps))
         want = list(inline.stream(pl_, 1, pl_.n_steps))
         assert len(got) == len(want) == pl_.n_steps - 1
-        for g, w in zip(got, want):
+        for step, (g, w) in enumerate(zip(got, want), start=1):
             assert np.array_equal(g, w)
-        assert pre.last_stats is not None and pre.last_stats["tasks"] > 0
+            assert g.shape[:-1] == pl_.step_indices(step).shape
 
     def test_double_wrap_is_not_applied_by_builder(self):
         """async_io() must not re-wrap an explicit PrefetchSource."""
