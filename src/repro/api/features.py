@@ -35,7 +35,6 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import spectra
@@ -531,23 +530,28 @@ def _spd_db(ctx: FeatureContext) -> jnp.ndarray:
 
 def _spd_update(db: jnp.ndarray, mask: jnp.ndarray) -> dict:
     """Per-record frame-count histogram: (batch, n_frames, n_bins) dB ->
-    {counts: (batch, n_bins, SPD_N_DB) int32}.  One flat segment-sum per
-    record instead of a dense one-hot, so memory stays O(n_frames*n_bins)
-    even for the paper's 60 s records."""
-    n_bins = db.shape[-1]
-    freq = jnp.broadcast_to(jnp.arange(n_bins), db.shape)
+    {counts: (batch, n_bins, SPD_N_DB) int32}, counted densely: for each
+    dB bin, the frames whose bin it is.  XLA fuses the compare into the
+    sum over frames, so the (batch, n_frames, n_bins, SPD_N_DB) one-hot
+    never exists and the cost is n_frames * n_bins * SPD_N_DB compares
+    whatever the data; a scatter-add would serialise the many frames a
+    noise floor puts into one bin.  Records stay independent, so a batch
+    sharded over a mesh is counted where it lies.
+
+    Invalid frames (out of range, NaN, masked record) take bin -1, which
+    no bin matches.  A frame a hair below SPD_DB_MAX can round to bin
+    SPD_N_DB in float32; it is counted where its flat ``freq * SPD_N_DB
+    + dbin`` id points, in the next frequency's lowest bin (dropped at
+    the last frequency), so committed histograms keep their bits."""
     dbin = jnp.floor((db - SPD_DB_MIN) / SPD_DB_STEP).astype(jnp.int32)
     valid = ((db >= SPD_DB_MIN) & (db < SPD_DB_MAX)
              & mask[:, None, None])
-    flat_ids = jnp.where(valid, freq * SPD_N_DB + dbin, n_bins * SPD_N_DB)
-
-    def one_record(ids):
-        h = jax.ops.segment_sum(
-            jnp.ones(ids.size, jnp.int32), ids.reshape(-1),
-            num_segments=n_bins * SPD_N_DB + 1)
-        return h[:-1].reshape(n_bins, SPD_N_DB)
-
-    return {"counts": jax.vmap(one_record)(flat_ids)}
+    dbin = jnp.where(valid, dbin, -1)
+    counts = jnp.sum(dbin[..., None] == jnp.arange(SPD_N_DB + 1), axis=1,
+                     dtype=jnp.int32)     # (batch, n_bins, SPD_N_DB + 1)
+    spill = jnp.pad(counts[:, :-1, SPD_N_DB:],
+                    ((0, 0), (1, 0), (0, SPD_N_DB - 1)))
+    return {"counts": counts[..., :SPD_N_DB] + spill}
 
 
 def _spd_finalize(state: dict[str, np.ndarray]) -> np.ndarray:

@@ -14,6 +14,7 @@ imports this file.  Interpret mode is switched off per test with
 TPU-lowered trace leaks into a later CPU test (or the reverse).
 """
 import collections
+import math
 import re
 
 import pytest
@@ -23,13 +24,15 @@ import jax.numpy as jnp
 from jax.sharding import (AxisType, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.api import engine, resolve_features
+from repro.api import Window, engine, resolve_features
 from repro.core.manifest import DatasetManifest
 from repro.core.params import PARAM_SET_1, PARAM_SET_2
 from repro.core.tol import band_matrix
 from repro.kernels import common, ct_rfft, events, framepsd, tol
 
 RECORDS = 8
+# records a step in each paper set's benchmark configuration
+CHUNK = {1: 8, 2: 32}
 # every feature of the smoke job; events adds the impulsive metrics
 FEATURES = ("welch", "spl", "tol", "percentiles", "ltsa", "spd", "minmax",
             "events", "impulsive")
@@ -140,18 +143,58 @@ class TestKernelsCompileForV5e:
         assert calls == {"detect_events": 1}
 
 
+def two_files(p):
+    return DatasetManifest(n_files=2,
+                           records_per_file=int(45 * 60 // p.record_size_sec),
+                           record_size=p.record_size, fs=p.fs, seed=42)
+
+
+def step_args(p, sharding, lead):
+    return (spec(sharding, lead + (p.record_size,), jnp.int16),
+            spec(sharding, lead, jnp.float32),
+            spec(sharding, lead, jnp.bool_))
+
+
 def compile_step(p, sharding, mesh=None, n_shards=1, chunk=4):
     """The engine's int16-transport step for every smoke feature, over
     two 45-minute files."""
-    m = DatasetManifest(n_files=2,
-                        records_per_file=int(45 * 60 // p.record_size_sec),
-                        record_size=p.record_size, fs=p.fs, seed=42)
-    step = engine.compile_step(tuple(resolve_features(FEATURES)), m, p,
-                               mesh, ("data",), True, False, True, "int16")
-    lead = (n_shards, chunk)
-    return step.lower(spec(sharding, lead + (p.record_size,), jnp.int16),
-                      spec(sharding, lead, jnp.float32),
-                      spec(sharding, lead, jnp.bool_)).compile()
+    step = engine.compile_step(tuple(resolve_features(FEATURES)),
+                               two_files(p), p, mesh, ("data",), True, False,
+                               True, "int16")
+    return step.lower(*step_args(p, sharding, (n_shards, chunk))).compile()
+
+
+def compile_reduce_update(p, sharding, chunk):
+    """The engine's carry update for every smoke feature's reductions,
+    per-file windows, fed the shapes the step hands it."""
+    m = two_files(p)
+    specs = tuple(resolve_features(FEATURES))
+    step = engine.compile_step(specs, m, p, None, ("data",), True, False,
+                               True, "int16")
+    lead = (1, chunk)
+    out = jax.eval_shape(step, *step_args(p, sharding, lead))
+    bindings, windows = engine.resolve_bindings(specs, m, p,
+                                                Window("file"))
+    state = jax.eval_shape(
+        lambda: engine._init_reduce_state(bindings, None))
+    on_chip = lambda x: spec(sharding, x.shape, x.dtype)   # noqa: E731
+    update = engine.compile_reduce_update(bindings, None, ("data",))
+    return update.lower(
+        jax.tree.map(on_chip, state), jax.tree.map(on_chip, out),
+        spec(sharding, lead, jnp.bool_),
+        {k: spec(sharding, lead, jnp.int32) for k in windows}).compile()
+
+
+def scatter_updates(hlo_text: str) -> list[int]:
+    """Element count of the updates operand of every scatter in a
+    compiled program, fused or not."""
+    shapes = dict(re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]", hlo_text))
+    sizes = []
+    for operands in re.findall(r"= \w+\[[\d,]*\][^ ]* scatter\(([^)]*)\)",
+                               hlo_text):
+        dims = shapes[operands.split(", ")[2]]
+        sizes.append(math.prod(int(d) for d in dims.split(",") if d))
+    return sizes
 
 
 @pytest.mark.usefixtures("compiled_for_tpu")
@@ -182,3 +225,20 @@ class TestStepCompilesForV5e:
             assert op not in text, op
         # each device's parameter is its own (1, chunk, record) slice
         assert f"s16[1,2,{PARAM_SET_1.record_size}]" in text
+
+
+@pytest.mark.usefixtures("compiled_for_tpu")
+class TestReduceUpdateCompilesForV5e:
+    @pytest.mark.parametrize("param_set", [1, 2])
+    def test_one_chip(self, one_chip, param_set):
+        """The SPD histogram is a dense count: no scatter takes one
+        update per spectrogram cell (the per-window sum over records
+        still scatters a record's histogram), and the count leaves the
+        one-hot over dB bins unmaterialised."""
+        p = PARAM_SET_1 if param_set == 1 else PARAM_SET_2
+        chunk = CHUNK[param_set]
+        compiled = compile_reduce_update(p, one_chip, chunk)
+        cells = chunk * p.frames_per_record * p.n_bins
+        sizes = scatter_updates(compiled.as_text())
+        assert all(n < cells for n in sizes), (sizes, cells)
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

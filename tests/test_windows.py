@@ -38,9 +38,11 @@ needs_hypothesis = pytest.mark.skipif(
     not HAVE_HYPOTHESIS,
     reason="optional dev dependency: pip install hypothesis")
 
+import jax
 import jax.numpy as jnp
 
 from repro import api
+from repro.api import features
 from repro.core import spectra
 from repro.core.manifest import DatasetManifest
 from repro.core.params import DepamParams
@@ -369,13 +371,82 @@ class TestWindowedProperties:
             with tempfile.TemporaryDirectory() as d:
                 build(sink=d, limit=limit).run()
                 resumed = build(sink=d).run()
-                for name in WINDOWED:
+                for name in WINDOWED:     # empty windows are NaN
                     assert np.array_equal(resumed.windows[name],
-                                          res.windows[name]), name
+                                          res.windows[name],
+                                          equal_nan=True), name
                 assert np.array_equal(
                     np.asarray(resumed["welch"]), res["welch"])
                 assert np.array_equal(resumed["mean_welch"],
                                       res["mean_welch"])
+
+
+def spd_counts_segment_sum(db, mask):
+    """The SPD update as a scatter: one flat segment-sum per record over
+    ``freq * SPD_N_DB + dbin`` ids, invalid frames to a dropped extra
+    segment — the oracle for the dense count."""
+    n_bins = db.shape[-1]
+    freq = jnp.broadcast_to(jnp.arange(n_bins), db.shape)
+    dbin = jnp.floor((db - api.SPD_DB_MIN) / api.SPD_DB_STEP).astype(
+        jnp.int32)
+    valid = ((db >= api.SPD_DB_MIN) & (db < api.SPD_DB_MAX)
+             & mask[:, None, None])
+    flat_ids = jnp.where(valid, freq * api.SPD_N_DB + dbin,
+                         n_bins * api.SPD_N_DB)
+
+    def one_record(ids):
+        h = jax.ops.segment_sum(
+            jnp.ones(ids.size, jnp.int32), ids.reshape(-1),
+            num_segments=n_bins * api.SPD_N_DB + 1)
+        return h[:-1].reshape(n_bins, api.SPD_N_DB)
+
+    return jax.vmap(one_record)(flat_ids)
+
+
+def _below(x):
+    return np.nextafter(np.float32(x), np.float32(-np.inf))
+
+
+SPD_EDGES = np.float32(api.SPD_DB_MIN) + np.float32(api.SPD_DB_STEP) \
+    * np.arange(api.SPD_N_DB + 1, dtype=np.float32)
+# dB values the binning decides at a boundary
+SPD_VALUES = {
+    "bin_edges": np.concatenate([SPD_EDGES, _below(SPD_EDGES),
+                                 np.nextafter(SPD_EDGES, np.float32(np.inf))]),
+    # _below(60) and the next float down round to bin SPD_N_DB in float32
+    "range_ends": np.float32([api.SPD_DB_MIN, api.SPD_DB_MAX,
+                              _below(api.SPD_DB_MAX),
+                              _below(_below(api.SPD_DB_MAX)),
+                              _below(api.SPD_DB_MIN), -1e4, 1e4]),
+    "nonfinite": np.float32([np.nan, np.inf, -np.inf, -75.0, 0.0]),
+}
+
+
+class TestSpdDenseCount:
+    """The dense SPD count against the segment-sum formulation, bitwise,
+    over paper-like shapes, odd frame counts and the dB values the
+    binning decides at a boundary."""
+
+    @pytest.mark.parametrize("shape", [(2, 5, 3), (3, 1537, 129),
+                                       (2, 80, 2049), (3, 997, 7)],
+                             ids=["tiny", "set1_like", "set2_like",
+                                  "prime_frames"])
+    @pytest.mark.parametrize("values", [None, *SPD_VALUES],
+                             ids=["noise", *SPD_VALUES])
+    @pytest.mark.parametrize("masked", [(), (1,)], ids=["all", "masked"])
+    def test_bitwise_equal_to_segment_sum(self, shape, values, masked):
+        rng = np.random.default_rng(7)
+        db = rng.normal(-75.0, 20.0, shape).astype(np.float32)
+        if values is not None:
+            hit = rng.random(shape) < 0.5
+            db[hit] = rng.choice(SPD_VALUES[values], hit.sum())
+        mask = np.ones(shape[0], bool)
+        mask[list(masked)] = False
+        want = jax.jit(spd_counts_segment_sum)(db, mask)
+        got = jax.jit(features._spd_update)(db, mask)["counts"]
+        assert got.dtype == want.dtype == jnp.int32
+        assert np.array_equal(got, want)
+        assert int(want.sum()) > 0
 
 
 class TestCustomReduction:
