@@ -62,7 +62,7 @@ from repro.core import spans
 from repro.core.params import DepamParams
 from repro.distributed import partition as partition_lib
 from .features import (EPOCH_WINDOW, FeatureContext, FeatureSpec,
-                       Reduction, StateField, Window)
+                       Reduction, StateField, Window, selects_order_stats)
 from .sinks import Sink
 from .sources import Source, synth_record
 
@@ -513,8 +513,18 @@ class JobStepper:
         self._overflowed = False     # event-capacity warning fired once
 
     # -- lifecycle ------------------------------------------------------
-    @spans.spanned("start")
     def start(self) -> "JobStepper":
+        """Bind, compile, open the sink, restore committed state (the
+        span ``start``, whose ``pct_select`` is 1 when the step selects
+        the percentiles' order statistics and 0 when it sorts them or
+        has none)."""
+        pct_select = self.use_kernels \
+            and any(s.name == "percentiles" for s in self.specs) \
+            and selects_order_stats(self.p.frames_per_record)
+        with spans.span("start", pct_select=int(pct_select)):
+            return self._start()
+
+    def _start(self) -> "JobStepper":
         """Bind, compile, open the sink, restore committed state.
 
         Resumable sinks may carry a committed plan whose geometry

@@ -5,9 +5,13 @@ its keyword arguments become the event's stats in the trace.  With no
 trace running a span costs about a microsecond, so spans are always on.
 Every span of step-scoped work carries ``step``, the plan step it
 belongs to, which joins one step's spans across the driver, loader and
-writer threads; ``start``, the job's, carries nothing.
+writer threads; ``start``, the job's, carries how the compiled step
+computes the percentiles.
 
-  ``start``          bind, compile, open the sink, seed the carry (job)
+  ``start``          bind, compile, open the sink, seed the carry (job;
+                     pct_select: 1 when the step selects the
+                     percentiles' order statistics, 0 when it sorts or
+                     has no percentiles)
   ``fetch_wait``     driver waits for the step's payload (step, records)
   ``dispatch``       masks, window ids, host->device copies, both
                      programs, the async device->host copies
@@ -27,8 +31,6 @@ for the device.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 
 PREFIX = "depam."
@@ -37,9 +39,3 @@ PREFIX = "depam."
 def span(name: str, **args) -> jax.profiler.TraceAnnotation:
     """The span ``depam.<name>``, with ``args`` as its stats."""
     return jax.profiler.TraceAnnotation(PREFIX + name, **args)
-
-
-def spanned(name: str):
-    """Decorator: every call of the function is the span ``depam.<name>``."""
-    return functools.partial(jax.profiler.annotate_function,
-                             name=PREFIX + name)

@@ -25,10 +25,11 @@ from jax.sharding import (AxisType, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.api import Window, engine, resolve_features
+from repro.api.features import _percentile_taps, selects_order_stats
 from repro.core.manifest import DatasetManifest
 from repro.core.params import PARAM_SET_1, PARAM_SET_2
 from repro.core.tol import band_matrix
-from repro.kernels import common, ct_rfft, events, framepsd, tol
+from repro.kernels import common, ct_rfft, events, framepsd, select, tol
 
 RECORDS = 8
 # records a step in each paper set's benchmark configuration
@@ -36,7 +37,8 @@ CHUNK = {1: 8, 2: 32}
 # every feature of the smoke job; events adds the impulsive metrics
 FEATURES = ("welch", "spl", "tol", "percentiles", "ltsa", "spd", "minmax",
             "events", "impulsive")
-KERNELS = {1: {"welch_psd", "frame_psd", "tol_levels", "detect_events"},
+KERNELS = {1: {"welch_psd", "frame_psd", "tol_levels", "detect_events",
+               "select_order_stats"},
            2: {"ct_frame_psd", "welch_mean", "tol_levels",
                "detect_events"}}
 
@@ -130,6 +132,16 @@ class TestKernelsCompileForV5e:
         calls = compile_kernels(lambda s: tol.tol_levels(s, bm, p), psd)
         assert calls == {"tol_levels": 1}
 
+    def test_select_order_stats_set1(self, one_chip):
+        p = PARAM_SET_1
+        db = spec(one_chip, (RECORDS, p.frames_per_record, p.n_bins),
+                  jnp.float32)
+        lo, hi, _, _ = _percentile_taps(p.frames_per_record)
+        calls = compile_kernels(
+            lambda x: select.select_order_stats(x, tuple(lo.tolist()),
+                                                tuple(hi.tolist())), db)
+        assert calls == {"select_order_stats": 1}
+
     def test_detect_events_set1(self, one_chip):
         p = PARAM_SET_1
         shape = (RECORDS, p.frames_per_record)
@@ -203,8 +215,14 @@ class TestStepCompilesForV5e:
     def test_one_chip(self, one_chip, param_set):
         p = PARAM_SET_1 if param_set == 1 else PARAM_SET_2
         compiled = compile_step(p, one_chip)
-        calls = common.tpu_kernel_calls(compiled.as_text())
+        text = compiled.as_text()
+        calls = common.tpu_kernel_calls(text)
         assert calls == dict.fromkeys(KERNELS[param_set], 1)
+        # set 1's 15,359 frames a record select the percentiles' order
+        # statistics; set 2's 80 sort
+        selects = selects_order_stats(p.frames_per_record)
+        assert selects == (param_set == 1)
+        assert (" sort(" in text) == (not selects)
         # fits a 16 GB chip with room for the pipeline's in-flight steps
         assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 

@@ -12,7 +12,8 @@ import pytest
 
 from repro import api
 from repro.core.manifest import DatasetManifest
-from repro.core.params import DepamParams
+from repro.api.features import selects_order_stats
+from repro.core.params import PARAM_SET_1, DepamParams
 from repro.core.store import FeatureStore
 from repro.data.wavio import write_dataset
 
@@ -121,3 +122,20 @@ def test_traced_outputs_equal_untraced(traced, tmp_path):
     for k in want.events:
         assert np.array_equal(got.events[k].counts, want.events[k].counts)
         assert np.array_equal(got.events[k].rows, want.events[k].rows)
+
+
+@pytest.mark.parametrize("params, selects", [
+    (PARAM_SET_1, 1),
+    (P, 0),
+], ids=["set1_frames", "below_crossover"])
+def test_start_reports_the_percentile_path(tmp_path, params, selects):
+    """``start`` carries ``pct_select``: 1 when the compiled step selects
+    the percentiles' order statistics (set 1's 15,359 frames a record),
+    0 when it sorts them (the 63 frames of quarter-second records)."""
+    m = DatasetManifest(n_files=1, records_per_file=2,
+                        record_size=params.record_size, fs=params.fs, seed=3)
+    with jax.profiler.trace(str(tmp_path)):
+        api.job(m, params).features("percentiles").chunk(2).run()
+    start, = [s for s in read_spans(tmp_path) if s.name == "start"]
+    assert start.stats["pct_select"] == selects
+    assert selects_order_stats(params.frames_per_record) == bool(selects)
